@@ -1,0 +1,60 @@
+"""Task fns with one planted fault each, and the control, run in place of
+the configuration's task fn (``repro.workloads.astro:stack_task``) by the
+fault tests and by ``bench/control.py --fault``."""
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+
+from repro.workloads.astro import stack_inputs, stack_task
+
+from bench.lib import layout
+from bench.lib.catalog import fid_of
+
+#: the configuration whose reference and calibration the control uses
+CONTROL_CONFIG = "sdss-fit-1chip"
+
+
+@functools.cache
+def _reference():
+    cfg = layout.config(layout.load_benchmark(), CONTROL_CONFIG)
+    return layout.reference(cfg), cfg["calibration"]
+
+
+def bf16(inputs: dict) -> np.ndarray:
+    """The control: the plain reference computed in bfloat16, the precision
+    below the configuration's float32, handed back as float32."""
+    import ml_dtypes
+
+    (oid, tiles), = inputs.items()
+    ref, cal = _reference()
+    out = ref.stack(np.asarray(tiles), [fid_of(oid)],
+                    sky_fraction=cal["sky_fraction"],
+                    shift_seed=cal["shift_seed"], dtype=ml_dtypes.bfloat16)
+    return np.asarray(out, np.float32)
+
+
+def half_batch(inputs: dict) -> np.ndarray:
+    """Half of the cutouts left out; the mean taken over the rest."""
+    from repro.kernels.stacking.ops import stack_rois
+
+    tiles, params = stack_inputs(inputs)
+    h = tiles.shape[0] // 2
+    return np.asarray(stack_rois(tiles[:h], *(p[:h] for p in params)))
+
+
+def altered(inputs: dict) -> np.ndarray:
+    """One pixel of every stack moved by a thousandth where it is made."""
+    out = np.array(stack_task(inputs))
+    out[out.shape[0] // 2, out.shape[1] // 2] *= 1.001
+    return out
+
+
+def dropped(inputs: dict) -> None:
+    """The stack is computed and never handed back."""
+    stack_task(inputs)
+
+
+for _fn in (bf16, half_batch, altered, dropped):
+    _fn.warmup = stack_task.warmup
