@@ -29,6 +29,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Optional
 
+from repro.obs.spans import bind, span
+
 from .cache import EvictionPolicy, ExecutorCache
 from .channel import CallbackChannel, Channel, ChannelClosed, LocalChannel
 from .index import IndexUpdate
@@ -110,7 +112,9 @@ class DispatchStats:
     ``RunReport.dispatch_stats`` and the dispatch benchmark.  ``lock_hold_s``
     accumulates time spent inside the runtime lock on the pump path -- the
     quantity the central-dispatcher bottleneck (Falkon's ~1k tasks/s wall)
-    is made of."""
+    is made of.  ``lock_wait_s`` is the other side of it: time spent asking
+    for the runtime lock before holding it, on the paths every task takes
+    (pump, index update, attempt completion)."""
 
     pump_calls: int = 0
     dispatch_batches: int = 0      # pumps that produced >= 1 dispatch
@@ -118,6 +122,7 @@ class DispatchStats:
     max_dispatch_batch: int = 0
     updates_applied: int = 0
     lock_hold_s: float = 0.0
+    lock_wait_s: float = 0.0
     frames_sent: int = 0           # wire frames (fleet); 0 in-process
     frames_recv: int = 0
     msgs_sent: int = 0             # logical messages inside those frames
@@ -134,6 +139,7 @@ class DispatchStats:
             "max_dispatch_batch": self.max_dispatch_batch,
             "updates_applied": self.updates_applied,
             "lock_hold_s": self.lock_hold_s,
+            "lock_wait_s": self.lock_wait_s,
             "frames_sent": self.frames_sent,
             "frames_recv": self.frames_recv,
             "msgs_sent": self.msgs_sent,
@@ -534,8 +540,10 @@ class DiffusionRuntime:
 
     def _pump(self) -> None:
         rec = self.recorder
+        asked = time.perf_counter()
         with self._lock:
             t0 = time.perf_counter()
+            self.stats.lock_wait_s += t0 - asked
             dispatches = self.dispatcher.next_dispatches(time.monotonic())
             self._note_pump_locked(len(dispatches), time.perf_counter() - t0)
             qlen = self.dispatcher.queue_len if rec is not None else 0
@@ -605,7 +613,9 @@ class DiffusionRuntime:
     def _on_update(self, upd: IndexUpdate) -> None:
         """Consumer side of the update seam (same code path for in-process
         sends and for updates arriving from fleet hosts)."""
+        asked = time.perf_counter()
         with self._lock:
+            self.stats.lock_wait_s += time.perf_counter() - asked
             self._on_update_locked(upd)
 
     def _on_update_locked(self, upd: IndexUpdate) -> None:
@@ -629,7 +639,6 @@ class DiffusionRuntime:
             m.inc("sched.pump_calls")
             if n_dispatches:
                 m.inc("sched.dispatches", n_dispatches)
-            m.observe("sched.pump_latency_s", hold_s)
 
     def dispatch_stats(self) -> dict:
         """Central-loop counter snapshot for RunReport / the benchmark."""
@@ -682,26 +691,35 @@ class DiffusionRuntime:
         ok = True
         acc = _InputLedger()
         rec = self.recorder
-        try:
-            inputs = {oid: self._resolve(acc, w, oid, disp.hints, tid=t.tid)
-                      for oid in t.inputs}
-            if rec is not None:
-                rec.emit("exec_start", tid=t.tid, eid=w.eid)
-            if t.fn is not None:
-                t.result = t.fn(**inputs) if _wants_kwargs(t.fn) else t.fn(inputs)
-            for ob in t.outputs:
-                # shape-only tasks (no fn) produce no real payload; admit the
-                # sentinel so downstream DAG reads still count as cache hits
-                # (a None payload would read as a miss on every lookup)
-                if t.fn is None:
-                    payload = SHAPE_ONLY_PAYLOAD
-                else:
-                    payload = t.result if len(t.outputs) == 1 else t.result[ob.oid]
-                self._emit(w.admit_update(ob, payload))
-                self.dispatcher.sizes[ob.oid] = ob.size_bytes
-        except Exception as e:  # noqa: BLE001 - task failure is data, not a crash
-            ok = False
-            t.result = e
+        # spans opened by this attempt (here and inside the task fn) are
+        # recorded only while recording is on (repro.obs.spans)
+        with bind(rec, t.tid, w.eid):
+            try:
+                inputs = {}
+                for oid in t.inputs:
+                    with span("task.resolve"):
+                        inputs[oid] = self._resolve(acc, w, oid, disp.hints,
+                                                    tid=t.tid)
+                if rec is not None:
+                    rec.emit("exec_start", tid=t.tid, eid=w.eid)
+                if t.fn is not None:
+                    t.result = (t.fn(**inputs) if _wants_kwargs(t.fn)
+                                else t.fn(inputs))
+                for ob in t.outputs:
+                    # shape-only tasks (no fn) produce no real payload; admit
+                    # the sentinel so downstream DAG reads still count as
+                    # cache hits (a None payload would read as a miss on
+                    # every lookup)
+                    if t.fn is None:
+                        payload = SHAPE_ONLY_PAYLOAD
+                    else:
+                        payload = (t.result if len(t.outputs) == 1
+                                   else t.result[ob.oid])
+                    self._emit(w.admit_update(ob, payload))
+                    self.dispatcher.sizes[ob.oid] = ob.size_bytes
+            except Exception as e:  # noqa: BLE001 - task failure is data
+                ok = False
+                t.result = e
         if rec is not None:
             rec.emit("exec_end", tid=t.tid, eid=w.eid, ok=ok)
         self._finish_attempt(w, t, acc, ok)
@@ -712,7 +730,9 @@ class DiffusionRuntime:
         whatever object ``self.workers`` maps the executor id to -- a
         thread worker here, a remote-executor proxy on a fleet -- and the
         identity check is the membership guard for both."""
+        asked = time.perf_counter()
         with self._lock:
+            self.stats.lock_wait_s += time.perf_counter() - asked
             self._finish_attempt_locked(w, t, acc, ok)
 
     def _finish_attempt_locked(self, w, t: Task, acc: _InputLedger,

@@ -55,6 +55,7 @@ from repro.core.index import ShardedIndex
 from repro.core.objects import DataObject
 from repro.core.runtime import (SHAPE_ONLY_PAYLOAD, CacheExecutorBase,
                                 _wants_kwargs)
+from repro.obs.spans import bind, span
 
 from .wire import SocketChannel, recv_msg, send_msg
 
@@ -286,26 +287,31 @@ class HostExecutor(CacheExecutorBase):
         rec = self.host.recorder
         tid = msg["tid"]
         ok, err, result = True, None, None
-        try:
-            inputs = {oid: self._resolve(oid, size, hints, routes, led,
-                                         tid=tid)
-                      for oid, size in msg["inputs"]}
-            if rec is not None:
-                rec.emit("exec_start", tid=tid, eid=self.eid)
-            fn = self.host.task_fn
-            if fn is not None:
-                result = fn(**inputs) if _wants_kwargs(fn) else fn(inputs)
-            for oid, osize in msg["outputs"]:
-                # shape-only tasks: admit the wire-stable sentinel (mirrors
-                # DiffusionRuntime._execute) so downstream DAG reads of the
-                # produced object still count as cache hits
-                if fn is None:
-                    payload = SHAPE_ONLY_PAYLOAD
-                else:
-                    payload = result if len(msg["outputs"]) == 1 else result[oid]
-                self._admit(DataObject(oid, int(osize)), payload)
-        except Exception as e:  # noqa: BLE001 - task failure is data
-            ok, err = False, f"{type(e).__name__}: {e}"
+        # spans of this attempt ride upstream with its other events
+        with bind(rec, tid, self.eid):
+            try:
+                inputs = {}
+                for oid, size in msg["inputs"]:
+                    with span("task.resolve"):
+                        inputs[oid] = self._resolve(oid, size, hints, routes,
+                                                    led, tid=tid)
+                if rec is not None:
+                    rec.emit("exec_start", tid=tid, eid=self.eid)
+                fn = self.host.task_fn
+                if fn is not None:
+                    result = fn(**inputs) if _wants_kwargs(fn) else fn(inputs)
+                for oid, osize in msg["outputs"]:
+                    # shape-only tasks: admit the wire-stable sentinel
+                    # (mirrors DiffusionRuntime._execute) so downstream DAG
+                    # reads of the produced object still count as cache hits
+                    if fn is None:
+                        payload = SHAPE_ONLY_PAYLOAD
+                    else:
+                        payload = (result if len(msg["outputs"]) == 1
+                                   else result[oid])
+                    self._admit(DataObject(oid, int(osize)), payload)
+            except Exception as e:  # noqa: BLE001 - task failure is data
+                ok, err = False, f"{type(e).__name__}: {e}"
         if rec is not None:
             rec.emit("exec_end", tid=tid, eid=self.eid, ok=ok)
         self.host.send_done(self.eid, tid, ok, led, err)

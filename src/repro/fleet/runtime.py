@@ -304,8 +304,10 @@ class FleetRuntime(DiffusionRuntime):
         forwarded index updates; outside the lock everything is grouped per
         host and shipped as bounded batch frames (wire_batch=1 degenerates
         to the one-frame-per-message wire)."""
+        asked = time.perf_counter()
         with self._lock:
             t0 = time.perf_counter()
+            self.stats.lock_wait_s += t0 - asked
             now = time.monotonic()
             dispatches = self.dispatcher.next_dispatches(now)
             # leases engage only on backlog: a non-empty queue after
@@ -393,7 +395,9 @@ class FleetRuntime(DiffusionRuntime):
         side half of the batching win (the send side cut the frame count;
         this cuts lock acquisitions and pump passes per completion storm)."""
         need_pump = False
+        asked = time.perf_counter()
         with self._lock:
+            self.stats.lock_wait_s += time.perf_counter() - asked
             for msg in msgs:
                 kind = msg["t"]
                 if kind == "updates":

@@ -16,9 +16,10 @@ from __future__ import annotations
 import re
 
 #: v1 = the PR-7 lifecycle kinds; v2 adds the DAG ready-set kinds
-#: ``task_held`` / ``task_ready`` (DESIGN.md §11).  load_events hard-errors
-#: on sinks written by any other version.
-EVENT_SCHEMA_VERSION = 2
+#: ``task_held`` / ``task_ready`` (DESIGN.md §11); v3 adds ``span`` (timed
+#: phases inside a task attempt, `repro.obs.spans`).  load_events
+#: hard-errors on sinks written by any other version.
+EVENT_SCHEMA_VERSION = 3
 
 # -- lifecycle kinds (per task) ---------------------------------------------
 TASK_ARRIVED = "task_arrived"        # Dispatcher.submit
@@ -39,13 +40,14 @@ TASK_REQUEUED = "task_requeued"      # retry / lease return / executor loss
 PUMP = "pump"                        # one dispatch pass: n bound, queue depth
 POOL = "pool"                        # executor pool transition: size, delta
 PROVISION = "provision"              # DRP decision: allocate, release
+SPAN = "span"                        # a timed phase of an attempt: name, dur
 
 LIFECYCLE_KINDS = (
     TASK_ARRIVED, TASK_HELD, TASK_READY, TASK_QUEUED, TASK_LEASED,
     TASK_CLAIMED, TASK_DISPATCHED,
     INPUT, EXEC_START, EXEC_END, TASK_DONE, TASK_FAILED, TASK_REQUEUED,
 )
-EVENT_KINDS = frozenset(LIFECYCLE_KINDS) | {PUMP, POOL, PROVISION}
+EVENT_KINDS = frozenset(LIFECYCLE_KINDS) | {PUMP, POOL, PROVISION, SPAN}
 
 # Input sources (the ``source`` field of INPUT events).
 SOURCE_LOCAL = "local"
@@ -112,14 +114,15 @@ def lifecycle_fingerprints(events):
     of the executor that ran the task, and ``inputs`` the sorted tuple of
     ``(oid, source, bytes)`` triples.  Two engines replaying the same trace
     batch-synchronously must produce EQUAL fingerprint maps even though their
-    clocks (and the interleaving across tasks) differ.
+    clocks (and the interleaving across tasks) differ.  Spans are timing,
+    not lifecycle, and are left out.
     """
     kinds: dict = {}
     execs: dict = {}
     inputs: dict = {}
     for e in events:
         tid = e.get("tid")
-        if tid is None or e["kind"] not in EVENT_KINDS:
+        if tid is None or e["kind"] not in LIFECYCLE_KINDS:
             continue
         k = e["kind"]
         if k == INPUT:

@@ -1,7 +1,8 @@
 """Chrome-trace (chrome://tracing / Perfetto) JSON export.
 
 One track per executor (``ph:"X"`` complete events spanning exec_start ->
-exec_end, named by task id), two wait tracks separating *dep-wait* (held on
+exec_end, named by task id, with the attempt's ``span`` events drawn as
+slices of their own on the same track), two wait tracks separating *dep-wait* (held on
 unmet producers: task_held -> task_ready) from *queue-wait* (runnable but
 unplaced: ready/queued -> task_dispatched), plus counter tracks (``ph:"C"``)
 for executor pool size, dispatcher queue depth, and cumulative
@@ -27,6 +28,7 @@ from .events import (
     POOL,
     PUMP,
     SOURCE_LOCAL,
+    SPAN,
     TASK_DISPATCHED,
     TASK_HELD,
     TASK_QUEUED,
@@ -85,6 +87,13 @@ def chrome_trace(events, path=None, samples=None):
                 "name": e["tid"], "cat": "task",
                 "ts": us(s["t"]), "dur": max(us(e["t"]) - us(s["t"]), 0.0),
                 "args": {"executor": eid},
+            })
+        elif k == SPAN:
+            trace.append({
+                "ph": "X", "pid": _PID, "tid": tid_of.get(e.get("eid"), 0),
+                "name": e["name"], "cat": "span",
+                "ts": us(e["t"]), "dur": round(e["dur"] * 1e6, 3),
+                "args": {"task": e.get("tid")},
             })
         elif k == TASK_HELD:
             held_at[e["tid"]] = e["t"]

@@ -23,6 +23,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.obs.spans import span
+
 MB = 1_000_000
 
 # Table 2: locality -> (num objects, num files)
@@ -137,11 +139,20 @@ def stack_inputs(inputs: dict) -> tuple[np.ndarray, tuple[np.ndarray, ...]]:
 def stack_task(inputs: dict) -> np.ndarray:
     """The task fn of both stages (``repro.workloads.astro:stack_task``):
     the mean coadd of every input cutout, computed by the Pallas stacking
-    kernel on the process's default device."""
+    kernel on the process's default device.  Its three phases are spans
+    (`repro.obs.spans`): host prep; the kernel's call on the host arrays,
+    which copies them to the device and launches; and the wait for the
+    result."""
     from repro.kernels.stacking.ops import stack_rois
 
-    tiles, params = stack_inputs(inputs)
-    return np.asarray(stack_rois(tiles, *params))
+    with span("stack.prep"):
+        tiles, params = stack_inputs(inputs)
+    with span("stack.launch"):
+        out = stack_rois(tiles, *params)
+    with span("stack.result"):
+        res = np.asarray(out)
+        del out         # the device buffer is released inside the span
+    return res
 
 
 def _host_device() -> dict:
