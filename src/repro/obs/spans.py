@@ -3,8 +3,8 @@ call, the JAX profiler.
 
 A span is opened where the work happens::
 
-    with span("stack.prep"):
-        tiles, params = stack_inputs(inputs)
+    with span("prep.host_copy"):
+        tiles = np.concatenate(arrays)
 
 and is free unless the calling thread has a sink bound: executor attempts
 bind ``(recorder, tid, eid)`` with :func:`bind` while recording is on

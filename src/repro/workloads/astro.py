@@ -90,6 +90,8 @@ def workload(locality: float, compressed: bool = True,
 SEED = 0
 #: 32-bit float ROI cutouts in one uncompressed FIT file (6 MB / 40 kB)
 TILES_PER_FIT = FIT_BYTES // (ROI_SHAPE[0] * ROI_SHAPE[1] * 4)
+#: a cutout's sky level, as a share of its mean
+SKY_FRACTION = 0.1
 
 
 def file_ids(oid: str) -> list[int]:
@@ -105,16 +107,24 @@ def make_tiles(oid: str, tiles_per_file: int) -> np.ndarray:
         .astype(np.float32)
 
 
-def coadd_params(tiles: np.ndarray, oids) -> tuple[np.ndarray, ...]:
-    """Per-cutout sky level, calibration and sub-pixel shift (dy, dx).  The
-    shifts are seeded by the input ids, never by a shared stream, so the
-    pixels do not depend on the order in which tasks ran."""
-    n = tiles.shape[0]
+def shifts(oids, n: int) -> tuple[np.ndarray, np.ndarray]:
+    """The sub-pixel shifts (dy, dx) of a stack's ``n`` cutouts, float32 in
+    [0, 1).  They are seeded by the input ids, never by a shared stream, so
+    the pixels do not depend on the order in which tasks ran."""
     rng = np.random.default_rng([SEED + 1, *(i for o in oids
                                              for i in file_ids(o))])
     dy = rng.random(n).astype(np.float32)
     dx = rng.random(n).astype(np.float32)
-    return (tiles.mean(axis=(1, 2)) * 0.1, np.ones(n, np.float32), dy, dx)
+    return dy, dx
+
+
+def coadd_params(tiles: np.ndarray, oids) -> tuple[np.ndarray, ...]:
+    """Per-cutout sky level, calibration and sub-pixel shift (dy, dx),
+    computed on the host: the sky is ``SKY_FRACTION`` of each cutout's mean
+    and the calibration is 1."""
+    dy, dx = shifts(oids, tiles.shape[0])
+    return (tiles.mean(axis=(1, 2)) * SKY_FRACTION,
+            np.ones(tiles.shape[0], np.float32), dy, dx)
 
 
 def is_stack(oid: str) -> bool:
@@ -123,9 +133,12 @@ def is_stack(oid: str) -> bool:
 
 
 def stack_inputs(inputs: dict) -> tuple[np.ndarray, tuple[np.ndarray, ...]]:
-    """What one task hands the kernel: the cutouts of all its inputs and
-    their coadd parameters.  Catalog files are calibrated and shifted;
-    produced stacks (the mosaic stage) are coadded as they are."""
+    """What one task hands the kernel, all of it made on the host: the
+    cutouts of all its inputs in a new array and their coadd parameters.
+    Catalog files are calibrated (the sky from :func:`coadd_params`) and
+    shifted; produced stacks (the mosaic stage) are coadded as they are.
+    :func:`stack_task` computes the same thing, the catalog files' sky on
+    the device."""
     oids = list(inputs)
     if all(is_stack(o) for o in oids):
         tiles = np.stack([np.asarray(v) for v in inputs.values()])
@@ -139,16 +152,37 @@ def stack_inputs(inputs: dict) -> tuple[np.ndarray, tuple[np.ndarray, ...]]:
 def stack_task(inputs: dict) -> np.ndarray:
     """The task fn of both stages (``repro.workloads.astro:stack_task``):
     the mean coadd of every input cutout, computed by the Pallas stacking
-    kernel on the process's default device.  Its three phases are spans
-    (`repro.obs.spans`): host prep; the kernel's call on the host arrays,
-    which copies them to the device and launches; and the wait for the
-    result."""
+    kernel on the process's default device.
+
+    One catalog file goes to the kernel's call as the array it is: the host
+    reads and writes none of its pixels, draws only the shifts, and the
+    device computes the sky.  Several catalog files are concatenated on the
+    host first, and their sky is computed on the device too; produced
+    stacks are stacked on the host with :func:`stack_inputs`' parameters.
+    Those host copies are the span ``prep.host_copy``.
+
+    The task fn's three phases are spans (`repro.obs.spans`): host prep;
+    the kernel's call on the host arrays, which copies them to the device
+    and launches; and the wait for the result."""
     from repro.kernels.stacking.ops import stack_rois
 
     with span("stack.prep"):
-        tiles, params = stack_inputs(inputs)
+        oids = list(inputs)
+        if all(is_stack(o) for o in oids):
+            with span("prep.host_copy"):
+                tiles, params = stack_inputs(inputs)
+            kw = {}
+        else:
+            arrays = [np.asarray(v) for v in inputs.values()]
+            if len(arrays) == 1:
+                tiles = arrays[0]
+            else:
+                with span("prep.host_copy"):
+                    tiles = np.concatenate(arrays)
+            params = shifts(oids, tiles.shape[0])
+            kw = {"sky_fraction": SKY_FRACTION}
     with span("stack.launch"):
-        out = stack_rois(tiles, *params)
+        out = stack_rois(tiles, *params, **kw)
     with span("stack.result"):
         res = np.asarray(out)
         del out         # the device buffer is released inside the span

@@ -11,6 +11,7 @@ compiler, which only one process may hold, so it must never happen while
 a module is imported or collected.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +57,35 @@ def test_stacking_kernel_compiles(one_chip, n):
         lambda r, s, c, y, x: stack_rois_fwd(r, s, c, y, x, interpret=False),
         [((n, 100, 100), f32)] + [((n,), f32)] * 4, one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("sky_on_device", [False, True])
+def test_stacking_wrapper_keeps_the_kernel_op_name(one_chip, monkeypatch,
+                                                   sky_on_device):
+    """The jitted wrapper compiles one file's stack (N=150) on both of its
+    calls, and the kernel's custom call keeps the wrapper's name,
+    ``stack_rois.N``, which is what a device trace finds it by."""
+    from repro.kernels.stacking import ops as st_ops
+
+    f32 = jnp.float32
+    n = 150
+    params = 2 if sky_on_device else 4
+    args = [jax.ShapeDtypeStruct((n, 100, 100), f32, sharding=one_chip)] + \
+        [jax.ShapeDtypeStruct((n,), f32, sharding=one_chip)] * params
+    kw = {"sky_fraction": 0.1} if sky_on_device else {}
+    # the wrapper reads the backend while it traces: steer it to the chip,
+    # and let no trace made for one backend answer for the other
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    try:
+        text = st_ops.stack_rois.lower(*args, **kw).compile().as_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    names = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert len(names) == 1 and re.match(r"^stack_rois(\.\d+)?$", names[0]), \
+        names
 
 
 def test_flash_attention_compiles_at_danube3_width(one_chip):

@@ -143,20 +143,75 @@ def test_stacking_integer_shift_exactness():
     np.testing.assert_allclose(np.asarray(out), np.asarray(rois.sum(0)))
 
 
-def test_astro_stack_task_coadds_files_and_stacks_like_the_reference():
-    """The paper application's task fn: catalog files are calibrated,
-    shifted and coadded; produced stacks (the mosaic) are coadded as-is."""
-    from repro.workloads.astro import make_tiles, stack_inputs, stack_task
+def _astro_inputs(kind: str) -> dict:
+    from repro.workloads.astro import make_tiles, stack_task
 
     files = {f"astro.g0.o{k}": make_tiles(f"astro.g0.o{k}", 3)
-             for k in range(2)}
-    assert np.array_equal(files["astro.g0.o1"], make_tiles("astro.g0.o1", 3))
-    stack = stack_task(files)
-    tiles, params = stack_inputs(files)
-    assert tiles.shape == (6, 100, 100) and params[2].std() > 0
-    np.testing.assert_allclose(
-        stack, np.asarray(stack_rois_ref(tiles, *params)) / 6, rtol=1e-5,
-        atol=1e-3)
-    stacks = {"astro.stack0": stack, "astro.stack1": stack + 1}
-    np.testing.assert_allclose(stack_task(stacks), stack + 0.5, rtol=1e-5,
-                               atol=1e-3)
+             for k in range(3)}
+    if kind == "one_file":
+        return {"astro.g0.o1": files["astro.g0.o1"]}
+    if kind == "three_files":
+        return files
+    stack = stack_task({"astro.g0.o0": files["astro.g0.o0"]})
+    return {"astro.stack0": stack, "astro.stack1": stack + 1}
+
+
+@pytest.mark.parametrize("kind", ["one_file", "three_files", "stacks"])
+def test_astro_stack_task_coadds_files_and_stacks_like_the_reference(kind):
+    """The paper application's task fn: catalog files are calibrated (the
+    sky computed on the device), shifted and coadded; produced stacks (the
+    mosaic) are coadded as they are.  The reference is the float32 oracle
+    on the host's parameters, ``stack_inputs``."""
+    from repro.workloads.astro import make_tiles, stack_inputs, stack_task
+
+    assert np.array_equal(make_tiles("astro.g0.o1", 3),
+                          make_tiles("astro.g0.o1", 3))
+    inputs = _astro_inputs(kind)
+    tiles, params = stack_inputs(inputs)
+    n = {"one_file": 3, "three_files": 9, "stacks": 2}[kind]
+    assert tiles.shape == (n, 100, 100)
+    assert (params[2].std() > 0) == (kind != "stacks")
+    ref = np.asarray(stack_rois_ref(tiles, *params)) / n
+    np.testing.assert_allclose(stack_task(inputs), ref, rtol=1e-5, atol=1e-3)
+    if kind == "stacks":
+        np.testing.assert_allclose(ref, inputs["astro.stack0"] + 0.5,
+                                   rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("kind", ["one_file", "three_files", "stacks"])
+def test_astro_stack_task_copies_pixels_on_the_host_only_for_several_inputs(
+        monkeypatch, kind):
+    """A one-file stack copies no pixel on the host: the kernel's call gets
+    the cached (read-only) array itself with the two shift vectors, the sky
+    is left to the device, and no ``prep.host_copy`` span is recorded.
+    Several files, or produced stacks, are copied inside one such span."""
+    from repro.obs import Recorder
+    from repro.obs.spans import bind
+    from repro.workloads.astro import stack_task
+
+    inputs = _astro_inputs(kind)
+    for v in inputs.values():
+        v.flags.writeable = False
+    calls = []
+    real = st_ops.stack_rois
+
+    def spy(tiles, *params, **kw):
+        calls.append((tiles, params, kw))
+        return real(tiles, *params, **kw)
+
+    monkeypatch.setattr(st_ops, "stack_rois", spy)
+    rec = Recorder()
+    with bind(rec, "t0", "w0"):
+        stack_task(inputs)
+    (tiles, params, kw), = calls
+    names = [e["name"] for e in rec.events()]
+    shared = any(np.shares_memory(tiles, v) for v in inputs.values())
+    if kind == "one_file":
+        assert shared and names == ["stack.prep", "stack.launch",
+                                    "stack.result"]
+    else:
+        assert not shared and names == ["prep.host_copy", "stack.prep",
+                                        "stack.launch", "stack.result"]
+    # catalog files leave the sky to the device; stacks have none
+    assert (len(params), "sky_fraction" in kw) == \
+        ((4, False) if kind == "stacks" else (2, True))

@@ -188,6 +188,22 @@ def test_each_stack_has_its_three_spans_and_a_resolve_per_input(stacking_run):
     assert covered >= 0.95 * total, (covered, total)
 
 
+def test_only_a_stack_of_several_files_copies_them_on_the_host(stacking_run):
+    """One file goes to the kernel as the cached array; two are
+    concatenated inside ``prep.host_copy``, which lies inside ``stack.prep``."""
+    tasks, rec, _ = stacking_run
+    evs = [e for e in rec.events() if e["kind"] == "span"]
+    for t in tasks:
+        prep = next(e for e in evs
+                    if e["tid"] == t.tid and e["name"] == "stack.prep")
+        copies = [e for e in evs
+                  if e["tid"] == t.tid and e["name"] == "prep.host_copy"]
+        assert len(copies) == (len(t.inputs) > 1), t.tid
+        assert all(prep["t"] <= e["t"] and
+                   e["t"] + e["dur"] <= prep["t"] + prep["dur"]
+                   for e in copies), t.tid
+
+
 def test_lock_wait_is_counted_beside_the_hold(stacking_run):
     _, _, st = stacking_run
     assert st["lock_wait_s"] >= 0.0
@@ -231,7 +247,8 @@ def test_a_sink_with_spans_round_trips(stacking_run, tmp_path):
     header, evs = load_events(path)
     assert header["schema_version"] == EVENT_SCHEMA_VERSION == 3
     assert evs == rec.events()
-    assert sum(e["kind"] == "span" for e in evs) == 12 * 3 + 18
+    # three phases a stack, a resolve an input, a host copy a two-file stack
+    assert sum(e["kind"] == "span" for e in evs) == 12 * 3 + 18 + 6
 
 
 def test_chrome_trace_draws_spans_on_their_executors_track(stacking_run):
@@ -242,7 +259,8 @@ def test_chrome_trace_draws_spans_on_their_executors_track(stacking_run):
     slices = [e for e in out if e["ph"] == "X" and e["cat"] == "span"]
     spans = [e for e in evs if e["kind"] == "span"]
     assert len(slices) == len(spans)
-    assert {s["name"] for s in slices} == {*STACK_SPANS, "task.resolve"}
+    assert {s["name"] for s in slices} == {*STACK_SPANS, "task.resolve",
+                                           "prep.host_copy"}
     by_task = {s["args"]["task"]: s["tid"] for s in slices}
     for e in spans:
         assert by_task[e["tid"]] == track[e["eid"]]
