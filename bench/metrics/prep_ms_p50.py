@@ -1,7 +1,7 @@
 """prep_ms_p50: median host prep of a stack (the program's ``stack.prep``
-span in ``repro.workloads.astro:stack_task``: the concatenate of the
-cutouts and their coadd parameters), over the stacks completed in the
-window."""
+span in ``repro.workloads.astro:stack_task``: the draw of the cutouts'
+shifts, and for a stack of several files or of produced stacks the host
+copy of their pixels too), over the stacks completed in the window."""
 from bench.lib.spanread import span_p50
 
 
