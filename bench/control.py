@@ -51,8 +51,8 @@ def readings(bench: dict, cell: dict, cfg: dict, traffic: dict, seed: int,
                                            ml_dtypes.bfloat16)
         row["bf16_inputs"] = max(
             check.gap(check.reference_stack(
-                refmod, _Rounded(catalog, ml_dtypes.bfloat16), cfg, o.fid),
-                check.reference_stack(refmod, catalog, cfg, o.fid))
+                refmod, _Rounded(catalog, ml_dtypes.bfloat16), cfg, o.fids),
+                check.reference_stack(refmod, catalog, cfg, o.fids))
             for o in sample)
     return row
 

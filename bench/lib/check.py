@@ -4,11 +4,11 @@ Once the window has closed and every offered stack has come (or the drain
 bound has passed), a sample of the stacks the window completed is drawn from
 the seed, a few through each input path the run used (a local cache hit, a
 peer fetch, a store read) and the rest at random.  Each stacked image the
-system produced is compared with the plain reference run over the file the
-stack named: the number compared is the widest pixel gap, as a share of the
-reference image's largest pixel, over the sample.  Beside it, counts that
-must be 0: stacks that failed, stacks that never came, sampled images that
-could not be read back.
+system produced is compared with the plain reference run over the files the
+stack named, in their order: the number compared is the widest pixel gap, as
+a share of the reference image's largest pixel, over the sample.  Beside it,
+counts that must be 0: stacks that failed, stacks that never came, sampled
+images that could not be read back.
 """
 from __future__ import annotations
 
@@ -18,11 +18,15 @@ SOURCES = ("local", "peer", "store")
 
 
 def source(task) -> str:
-    if task.cache_hits:
-        return "local"
+    """The slowest path any input of the task took: store, then peer, then
+    local, so "local" means every input was a local hit.  Of the inputs the
+    task's ledger counts as misses, those that were no peer hit came from
+    the store."""
+    if task.cache_misses > task.peer_hits:
+        return "store"
     if task.peer_hits:
         return "peer"
-    return "store"
+    return "local"
 
 
 def draw(offered: list, seed: int, n: int, per_source: int) -> list:
@@ -46,10 +50,10 @@ def gap(got: np.ndarray, ref: np.ndarray) -> float:
                  / np.max(np.abs(ref)))
 
 
-def reference_stack(refmod, catalog, cfg: dict, fid: int, dtype=np.float64):
+def reference_stack(refmod, catalog, cfg: dict, fids, dtype=np.float64):
     c = cfg["calibration"]
-    return refmod.stack(catalog.file(fid), [fid],
-                        sky_fraction=c["sky_fraction"],
+    tiles = np.concatenate([catalog.file(f) for f in fids])
+    return refmod.stack(tiles, list(fids), sky_fraction=c["sky_fraction"],
                         shift_seed=c["shift_seed"], dtype=dtype)
 
 
@@ -63,13 +67,13 @@ def compare(sample: list, results: dict, refmod, catalog, cfg: dict) -> dict:
             continue
         by_source[source(o.task)] += 1
         worst = max(worst, gap(got, reference_stack(refmod, catalog, cfg,
-                                                    o.fid)))
+                                                    o.fids)))
     return {"pixel_gap": worst, "missing": missing, "compared": by_source}
 
 
 def control_gap(sample: list, refmod, catalog, cfg: dict, dtype) -> float:
     """The same number for the reference itself in a lower precision."""
-    return max(gap(np.asarray(reference_stack(refmod, catalog, cfg, o.fid,
+    return max(gap(np.asarray(reference_stack(refmod, catalog, cfg, o.fids,
                                               dtype), np.float64),
-                   reference_stack(refmod, catalog, cfg, o.fid))
+                   reference_stack(refmod, catalog, cfg, o.fids))
                for o in sample)

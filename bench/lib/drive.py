@@ -5,7 +5,9 @@ The system under test is built as its users build it: the configuration's
 ``ExperimentSpec`` through ``RuntimeEngine``, a ``DiffusionRuntime`` of
 executor threads on one chip or a ``FleetRuntime`` of host processes with a
 chip each, running the configuration's task fn.  The benchmark only puts the
-catalog's files in the store and submits tasks, as a client would.
+catalog's files in the store and submits tasks, as a client would: one task
+a stack, whose inputs are the stack's ``files_per_stack`` files in the order
+the traffic drew them.
 """
 from __future__ import annotations
 
@@ -45,10 +47,10 @@ class NoChip(RuntimeError):
 
 @dataclass
 class Offered:
-    """One stack the window offered, and when it was due."""
+    """One stack the window offered, its file ids, and when it was due."""
 
     task: Any
-    fid: int
+    fids: tuple[int, ...]
     due: float
 
 
@@ -80,7 +82,7 @@ class RunRecord:
         for o in self.offered:
             t = o.task
             out.append({
-                "tid": t.tid, "fid": o.fid, "due": o.due,
+                "tid": t.tid, "fids": o.fids, "due": o.due,
                 "submit": t.submit_time, "dispatch": t.dispatch_time,
                 "end": t.end_time, "state": t.state.value,
                 "ok": t.state.value == "done",
@@ -186,13 +188,14 @@ class System:
         self.recorder = self.engine.recorder
         self._n = 0
 
-    def task(self, fid: int):
+    def task(self, fids: tuple[int, ...]):
         from repro.core.objects import DataObject, Task
 
         self._n += 1
         outs = ((DataObject(f"stack.{self._n}", self.out_bytes),)
                 if self.out_bytes else ())
-        return Task(inputs=(oid_of(fid),), outputs=outs, fn=self.task_fn)
+        return Task(inputs=tuple(oid_of(f) for f in fids), outputs=outs,
+                    fn=self.task_fn)
 
     def done_count(self) -> int:
         d = self.rt.dispatcher
@@ -218,7 +221,7 @@ class Run:
     def __init__(self, cell: str, cfg: dict, traffic: dict, seed: int,
                  seconds: float, trace: bool) -> None:
         self.cfg, self.seed, self.trace = cfg, seed, trace
-        self.traffic = Traffic(traffic, seed)
+        self.traffic = Traffic(traffic, seed, cfg["files_per_stack"])
         self.rec = RunRecord(cell=cell, seconds=seconds,
                              traffic_kind=self.traffic.arrivals["kind"])
         self.system: Optional[System] = None
@@ -271,10 +274,10 @@ class Run:
                                       self.catalog.file(fid))
 
     def _next_task(self) -> Offered:
-        fid, first = next(self._stream)
-        if first:
+        fids, first = next(self._stream)
+        for fid in first:
             self._put(fid)
-        return Offered(self.system.task(fid), fid, 0.0)
+        return Offered(self.system.task(fids), fids, 0.0)
 
     # -- the measured window --------------------------------------------------
     def window(self) -> None:

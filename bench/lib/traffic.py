@@ -2,10 +2,11 @@
 
 A traffic file (``bench/traffic/<mix>.json``) holds only parameters:
 
-  segment      {"stacks": S, "files": F}: S stacks over F files, one file per
-               stack, every file read S // F or S // F + 1 times (which files
-               get the extra read is drawn from the seed), in shuffled order,
-               as ``StackingTrace`` shuffles a Table 2 trace.
+  segment      {"stacks": S, "files": F}: S stacks over F files.  Each stack
+               has a primary file, and every file is primary S // F or
+               S // F + 1 times (which files get the extra read is drawn from
+               the seed), in shuffled order, as ``StackingTrace`` shuffles a
+               Table 2 trace.
   fresh_files  true: every segment reads F files no segment read before, so
                a segment's hit ratio does not depend on how many came before;
                false: every segment reads the same F files (a fixed catalog
@@ -18,8 +19,12 @@ A traffic file (``bench/traffic/<mix>.json``) holds only parameters:
                from G, scaled to fill the window exactly; the run's seed
                only orders them, so every seed offers the same arrivals.
 
-The stream of a seed is the same in every run: segment ``k`` depends only on
-(seed, k).
+k, the files a stack reads, is the configuration's ``files_per_stack``.  A
+stack reads its primary's stack group: the k consecutive file ids of the
+segment that hold the primary (the last group wraps to the segment's first
+ids), primary first, then the group's next ids in turn.  The stream of a
+seed is the same in every run: segment ``n`` depends only on (seed, n), and
+its primaries do not depend on k.
 """
 from __future__ import annotations
 
@@ -36,15 +41,21 @@ ARRIVAL_KINDS = ("backlog", "poisson")
 class Segment:
     index: int
     files: tuple[int, ...]      # file ids, in the order they are first put
-    reads: tuple[int, ...]      # the file id each stack reads, in order
+    stacks: tuple[tuple[int, ...], ...]     # each stack's k file ids, primary
+                                            # first
 
 
 class Traffic:
-    def __init__(self, params: dict, seed: int) -> None:
+    def __init__(self, params: dict, seed: int,
+                 files_per_stack: int = 1) -> None:
         seg = params["segment"]
         self.stacks, self.files = int(seg["stacks"]), int(seg["files"])
         if not 1 <= self.files <= self.stacks:
             raise ValueError(f"a segment needs 1 <= files <= stacks: {seg}")
+        self.k = int(files_per_stack)
+        if not 1 <= self.k <= self.files:
+            raise ValueError(f"files_per_stack {files_per_stack!r} is not in "
+                             f"[1, {self.files}], the files of a segment")
         self.fresh = bool(params["fresh_files"])
         self.warmup_stacks = int(params["warmup_stacks"])
         self.arrivals = dict(params["arrivals"])
@@ -53,31 +64,36 @@ class Traffic:
                              f" (known: {ARRIVAL_KINDS})")
         self.seed = seed
 
-    def segment(self, k: int) -> Segment:
-        rng = np.random.default_rng([self.seed, 0x5E6, k])
-        base = k * self.files if self.fresh else 0
+    def segment(self, n: int) -> Segment:
+        rng = np.random.default_rng([self.seed, 0x5E6, n])
+        base = n * self.files if self.fresh else 0
         fids = np.arange(base, base + self.files)
         reads = np.repeat(fids, self.stacks // self.files)
         extra = rng.choice(fids, self.stacks % self.files, replace=False)
         reads = np.concatenate([reads, extra])
         rng.shuffle(reads)
-        return Segment(k, tuple(int(f) for f in fids),
-                       tuple(int(f) for f in reads))
+        k, F = self.k, self.files
+        stacks = tuple(tuple(base + (p - p % k + (p + j) % k) % F
+                             for j in range(k))
+                       for p in (int(f) - base for f in reads))
+        return Segment(n, tuple(int(f) for f in fids), stacks)
 
-    def stream(self) -> Iterator[tuple[int, bool]]:
-        """Endless (file id, first read of this file in its segment)."""
-        k = 0
+    def stream(self) -> Iterator[tuple[tuple[int, ...], tuple[int, ...]]]:
+        """Endless (a stack's file ids, those of them read for the first time
+        in their segment)."""
+        n = 0
         while True:
             seen: set[int] = set()
-            for fid in self.segment(k).reads:
-                first = fid not in seen
-                seen.add(fid)
-                yield fid, first
-            k += 1
+            for fids in self.segment(n).stacks:
+                first = tuple(f for f in fids if f not in seen)
+                seen.update(first)
+                yield fids, first
+            n += 1
 
     def ideal_hit_ratio(self) -> float:
-        """1 - F/S: every read after a file's first is a hit."""
-        return 1.0 - self.files / self.stacks
+        """1 - F/(S k): every read after a file's first is a hit, and every
+        file of a segment is some stack's primary."""
+        return 1.0 - self.files / (self.stacks * self.k)
 
     def arrival_offsets(self, seconds: float) -> np.ndarray:
         """Open loop: due times, in seconds after the window opens."""

@@ -3,10 +3,11 @@
   python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Sets the cell's configuration up on the chip(s) it asks for, warms it up,
-offers the cell's traffic for ``--seconds``, waits for every stack offered,
-reads the device's peak memory, frees the system, and checks a sample of the
-stacked images against the plain reference.  The last line of standard
-output is one JSON object: ``correct``, ``attempted``, ``failed``,
+offers the cell's traffic for ``--seconds`` (stacks of the configuration's
+``files_per_stack`` files each), waits for every stack offered, reads the
+device's peak memory, frees the system, and checks a sample of the stacked
+images against the plain reference over the same files.  The last line of
+standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics, read from a profiled stretch and the runtime's events),
 ``device``, with ``--trace 1`` a ``breakdown``, and last ``checks``: each

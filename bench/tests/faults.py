@@ -27,9 +27,9 @@ def bf16(inputs: dict) -> np.ndarray:
     below the configuration's float32, handed back as float32."""
     import ml_dtypes
 
-    (oid, tiles), = inputs.items()
     ref, cal = _reference()
-    out = ref.stack(np.asarray(tiles), [fid_of(oid)],
+    tiles = np.concatenate([np.asarray(v) for v in inputs.values()])
+    out = ref.stack(tiles, [fid_of(o) for o in inputs],
                     sky_fraction=cal["sky_fraction"],
                     shift_seed=cal["shift_seed"], dtype=ml_dtypes.bfloat16)
     return np.asarray(out, np.float32)
@@ -56,5 +56,23 @@ def dropped(inputs: dict) -> None:
     stack_task(inputs)
 
 
-for _fn in (bf16, half_batch, altered, dropped):
+def input_dropped(inputs: dict) -> np.ndarray:
+    """A stack of several files made without its last file."""
+    return stack_task(dict(list(inputs.items())[:-1]))
+
+
+def inputs_reversed(inputs: dict) -> np.ndarray:
+    """A stack of several files made from its files in reverse order."""
+    return stack_task(dict(reversed(list(inputs.items()))))
+
+
+def input_replaced(inputs: dict) -> np.ndarray:
+    """A stack of several files whose last file's cutouts are another file
+    of the segment's: the first file's, read twice."""
+    oids = list(inputs)
+    return stack_task({**inputs, oids[-1]: inputs[oids[0]]})
+
+
+for _fn in (bf16, half_batch, altered, dropped, input_dropped,
+            inputs_reversed, input_replaced):
     _fn.warmup = stack_task.warmup

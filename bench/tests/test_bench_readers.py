@@ -27,7 +27,7 @@ def _record(kind="backlog"):
                   end=due + 0.0055 + i * 1e-3, hits=0 if i % 10 == 0 else 1,
                   misses=1 if i % 10 == 0 else 0, c2c=6_000_000 * (i % 10 == 0),
                   state="failed" if i == 7 else "done")
-        rec.offered.append(Offered(t, i, due))
+        rec.offered.append(Offered(t, (i,), due))
     rec.events = []
     for i in range(40):
         rec.events += [{"kind": "exec_start", "tid": f"t{i}", "t": float(i)},

@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from bench.lib import layout
+from bench.lib import check, layout
 from bench.run import execute
 
 B = layout.load_benchmark()
@@ -33,7 +33,7 @@ FLEET = {"name": "sdss-l30-fleet4", "config": "sdss-fit-fleet4",
          "traffic": "l30-catalog-backlog", "chips": 4}
 
 
-def _small(cell_name, hosts=0):
+def _small(cell_name, hosts=0, k=1):
     if hosts:
         cell = FLEET
         cfg = json.loads((layout.BENCH / "configs" /
@@ -42,8 +42,11 @@ def _small(cell_name, hosts=0):
         cell = layout.cell(B, cell_name)
         cfg = copy.deepcopy(layout.config(B, cell["config"]))
     cfg["correctness"].update(sample=8, per_source=2)
-    # a small catalog: 8 files of bytes of their own, 30 reads each
+    # a small catalog: 8 files of bytes of their own, 30 reads each; a stack
+    # of k files coadds as many cutouts as a stack of one
+    cfg["files_per_stack"] = k
     cfg["assumed"]["distinct_files"] = 8
+    cfg["assumed"]["tiles_per_file"] //= k
     if hosts:
         cfg["spec"].update(hosts=hosts, threads_per_host=1)
         cfg["spec"]["cluster"]["n_nodes"] = hosts
@@ -71,8 +74,9 @@ def test_without_a_tpu_the_command_fails_and_prints_no_result():
     assert "no chip" in p.stderr
 
 
-def test_a_sound_one_chip_run_is_correct_and_names_its_device():
-    out = _run(*_small("sdss-l30-backlog"))
+@pytest.mark.parametrize("k", [1, 3])
+def test_a_sound_one_chip_run_is_correct_and_names_its_device(k):
+    out = _run(*_small("sdss-l30-backlog", k=k))
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
     assert out["device"]["platform"] == "cpu" and out["device"]["count"] >= 1
@@ -105,9 +109,35 @@ def test_a_fault_in_the_timed_path_makes_the_run_incorrect(fault, check):
     assert out["checks"][check]["value"] > out["checks"][check]["limit"]
 
 
-@pytest.mark.parametrize("fault", [None, "dropped"])
-def test_fleet_pixels_come_back_from_the_host_that_caches_them(fault):
-    cell, cfg, traffic = _small("sdss-l30-fleet4", hosts=2)
+@pytest.mark.parametrize("fault", ["input_dropped", "inputs_reversed",
+                                   "input_replaced"])
+def test_a_fault_in_a_stack_of_several_files_makes_the_run_incorrect(fault):
+    cell, cfg, traffic = _small("sdss-l30-backlog", k=3)
+    cfg["task_fn"] = f"bench.tests.faults:{fault}"
+    out = _run(cell, cfg, traffic)
+    assert out["correct"] is False
+    assert out["checks"]["pixel_gap"]["value"] > \
+        out["checks"]["pixel_gap"]["limit"]
+
+
+@pytest.mark.parametrize("hits,peers,misses,path", [
+    (1, 0, 0, "local"), (0, 1, 1, "peer"), (0, 0, 1, "store"),
+    (3, 0, 0, "local"), (2, 1, 1, "peer"), (2, 0, 1, "store"),
+    (1, 1, 2, "store"), (0, 3, 3, "peer"),
+])
+def test_a_stacks_input_path_is_the_slowest_any_of_its_files_took(
+        hits, peers, misses, path):
+    from repro.core.objects import Task
+
+    k = hits + misses
+    t = Task(inputs=tuple(f"fit.{i}" for i in range(k)))
+    t.cache_hits, t.peer_hits, t.cache_misses = hits, peers, misses
+    assert check.source(t) == path
+
+
+@pytest.mark.parametrize("k,fault", [(1, None), (1, "dropped"), (3, None)])
+def test_fleet_pixels_come_back_from_the_host_that_caches_them(k, fault):
+    cell, cfg, traffic = _small("sdss-l30-fleet4", hosts=2, k=k)
     if fault:
         cfg["task_fn"] = f"bench.tests.faults:{fault}"
     out = _run(cell, cfg, traffic)
